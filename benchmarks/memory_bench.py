@@ -190,7 +190,7 @@ def _ivf_rows(C: int, E: int, iters: int, rng) -> dict:
 
 
 def _sharded_parity(shards: int) -> dict:
-    """Run the multi-shard bit-parity selftest in a subprocess (forcing
+    """Run the multi-shard parity selftest in a subprocess (forcing
     host placeholder devices must happen before jax initializes)."""
     flags = (os.environ.get("XLA_FLAGS", "")
              + f" --xla_force_host_platform_device_count={shards}").strip()
@@ -199,7 +199,7 @@ def _sharded_parity(shards: int) -> dict:
     r = subprocess.run([sys.executable, "-m", "repro.core.memory_sharded"],
                        capture_output=True, text=True, env=env, timeout=600)
     if r.returncode != 0:
-        return {"shards": shards, "bit_identical": False,
+        return {"shards": shards, "rows_meta_identical": False,
                 "error": (r.stdout + r.stderr)[-500:]}
     return json.loads(r.stdout.strip().splitlines()[-1])
 
@@ -304,8 +304,8 @@ def main() -> None:
           f"{top[f'topk{TOPK}_over_top1_batch32']}x top-1; ivf "
           f"{top[f'ivf_speedup_single_topk{TOPK}']}x exact at recall@"
           f"{TOPK} {top[f'ivf_recall_at_{TOPK}']}; "
-          f"sharded bit_identical="
-          f"{sharded.get('bit_identical')} → {args.out}", file=sys.stderr)
+          f"sharded rows_meta_identical="
+          f"{sharded.get('rows_meta_identical')} → {args.out}", file=sys.stderr)
 
 
 if __name__ == "__main__":

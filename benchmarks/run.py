@@ -13,6 +13,7 @@ import time
 import traceback
 
 from benchmarks.common import print
+from repro.cache import enable_compile_cache
 
 SECTIONS = [
     ("fig4_rar_vs_baselines", "Fig 4: RAR vs baselines, professional law"),
@@ -27,6 +28,7 @@ SECTIONS = [
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated module names")

@@ -294,11 +294,9 @@ class IVFMemory:
                 raise ValueError(f"probes={probes} exceeds the "
                                  f"{clusters // S} clusters per shard")
         self._ep = store.emb.shape[1]
-        if bucket_cap is None:
-            # ~4x the average cluster occupancy of a full ring: skewed
-            # clusters overflow (FIFO bucket eviction) only past that
-            bucket_cap = max(8, math.ceil(4 * C / self.clusters))
-        self.bucket_cap = _round_up(int(bucket_cap), 8)
+        # a default bucket width follows the capacity, through grow() too
+        self._auto_bucket_cap = bucket_cap is None
+        self.bucket_cap = self._bucket_cap_for(C, bucket_cap)
         self.offload = bool(offload)
         self.cold_after = int(cold_after)
         self._ptr_host = int(jax.device_get(store.ptr))
@@ -328,6 +326,13 @@ class IVFMemory:
         self._assign_dev = None
         if self._ptr_host:
             self.reindex()
+
+    def _bucket_cap_for(self, capacity: int, bucket_cap: int | None) -> int:
+        if bucket_cap is None:
+            # ~4x the average cluster occupancy of a full ring: skewed
+            # clusters overflow (FIFO bucket eviction) only past that
+            bucket_cap = max(8, math.ceil(4 * capacity / self.clusters))
+        return _round_up(int(bucket_cap), 8)
 
     # -- delegation -----------------------------------------------------
     @property
@@ -646,7 +651,9 @@ class IVFMemory:
     # -- grow-in-place --------------------------------------------------
     def grow(self, new_capacity: int):
         """Grow the backing store (:func:`repro.core.memory.grow_memory`)
-        and re-bucket the clusters against the re-laid-out slots.
+        and re-bucket the clusters against the re-laid-out slots (a
+        default bucket width widens with the capacity, so probing every
+        cluster stays the exact scan).
         Returns ``(self, remap)`` — the :meth:`CommitStream.grow`
         contract."""
         if self._sharded:
@@ -655,6 +662,8 @@ class IVFMemory:
         self.store, remap = mem.grow_memory(self.store, new_capacity)
         self._ptr_host = int(jax.device_get(self.store.ptr))
         C = self.store.capacity
+        if self._auto_bucket_cap:
+            self.bucket_cap = self._bucket_cap_for(C, None)
         self._assign = np.full(C, -1, np.int32)
         if self.offload:
             self._emb_host = np.zeros((C, self._ep), np.float32)

@@ -54,8 +54,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+import numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import memory as mem
 from repro.kernels import ops as kops
@@ -64,13 +64,32 @@ from repro.kernels.memory_topk import (MASK_VALID, _select_topk,
 
 AXIS = "mem"
 
+# How far a sharded sim may sit from the single-device one: the same f32
+# row dot product, computed in matrix products of different shapes, so
+# summed in another order. The products' magnitudes of two unit vectors
+# add up to at most 1, so the rounding difference is counted in ulps of
+# 1.0 (2**-23), not of the result, which may be near zero.
+SIM_MAX_ULP = 4
+
+
+def sims_agree(a, b) -> bool:
+    """True where two stores' sims for the same rows agree to within
+    :data:`SIM_MAX_ULP` ulps of 1.0 (exact for the -2.0/-3.0
+    sentinels, which are never computed)."""
+    d = np.abs(np.asarray(a, np.float64) - np.asarray(b, np.float64))
+    return bool(np.all(d <= SIM_MAX_ULP * np.spacing(np.float32(1))))
+
 
 def make_memory_mesh(shards: int | None = None,
                      devices: list | None = None) -> Mesh:
     """1-D mesh over the devices carrying the store."""
     devices = devices if devices is not None else jax.devices()
     shards = shards or len(devices)
-    return jax.make_mesh((shards,), (AXIS,), devices=devices[:shards])
+    # Auto axes: the store's eager scatters (commits, flag updates,
+    # ``to_single_device``) rely on propagated shardings, which Explicit
+    # axes (``make_mesh``'s default) reject
+    return jax.make_mesh((shards,), (AXIS,), axis_types=(AxisType.Auto,),
+                         devices=devices[:shards])
 
 
 # ---------------------------------------------------------------------------
@@ -93,10 +112,10 @@ def _query_sharded(mesh: Mesh, cs: int, required: int,
         s = jnp.argmax(sims)            # first max → lowest shard on ties
         return sims[s], s.astype(jnp.int32) * cs + idxs[s], bitss[s]
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(AXIS, None), P(AXIS, None), P()),
-                     out_specs=(P(), P(), P()), check_rep=False
-                     )(emb, mask, q)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(AXIS, None), P(AXIS, None), P()),
+                         out_specs=(P(), P(), P()), check_vma=False
+                         )(emb, mask, q)
 
 
 @partial(jax.jit, static_argnames=("mesh", "cs", "required"))
@@ -115,10 +134,10 @@ def _query_batch_sharded(mesh: Mesh, cs: int, required: int,
         take = lambda a: jnp.take_along_axis(a, s[None], axis=0)[0]  # noqa: E731
         return take(sims), s.astype(jnp.int32) * cs + take(idxs), take(bitss)
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(AXIS, None), P(AXIS, None), P()),
-                     out_specs=(P(), P(), P()), check_rep=False
-                     )(emb, mask, qs)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(AXIS, None), P(AXIS, None), P()),
+                         out_specs=(P(), P(), P()), check_vma=False
+                         )(emb, mask, qs)
 
 
 def _merge_topk(sims: jax.Array, rows: jax.Array, bits: jax.Array, k: int
@@ -153,10 +172,10 @@ def _query_topk_sharded(mesh: Mesh, cs: int, k: int, required: int,
         cand_b = jax.lax.all_gather(bits, AXIS).reshape(S * k)
         return _merge_topk(cand_s, cand_r, cand_b, k)
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(AXIS, None), P(AXIS, None), P()),
-                     out_specs=(P(), P(), P()), check_rep=False
-                     )(emb, mask, q)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(AXIS, None), P(AXIS, None), P()),
+                         out_specs=(P(), P(), P()), check_vma=False
+                         )(emb, mask, q)
 
 
 @partial(jax.jit, static_argnames=("mesh", "cs", "k", "required"))
@@ -180,10 +199,10 @@ def _query_topk_batch_sharded(mesh: Mesh, cs: int, k: int, required: int,
             gather(bits), k)                                   # (k, B)
         return out_s.T, out_r.T, out_b.T
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(AXIS, None), P(AXIS, None), P()),
-                     out_specs=(P(), P(), P()), check_rep=False
-                     )(emb, mask, qs)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(AXIS, None), P(AXIS, None), P()),
+                         out_specs=(P(), P(), P()), check_vma=False
+                         )(emb, mask, qs)
 
 
 @partial(jax.jit, static_argnames=("mesh", "cs", "csp"))
@@ -203,10 +222,11 @@ def _commit_sharded(mesh: Mesh, cs: int, csp: int,
         return (emb_s.at[rows].set(rows_p, mode="drop"),
                 mask_s.at[rows, 0].set(bits, mode="drop"))
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(AXIS, None), P(AXIS, None), P(), P(), P()),
-                     out_specs=(P(AXIS, None), P(AXIS, None)),
-                     check_rep=False)(emb, mask, rows_p, bits, slots)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(AXIS, None), P(AXIS, None),
+                                   P(), P(), P()),
+                         out_specs=(P(AXIS, None), P(AXIS, None)),
+                         check_vma=False)(emb, mask, rows_p, bits, slots)
 
 
 @jax.jit
@@ -387,14 +407,17 @@ def parity_selftest(capacity: int = 64, embed_dim: int = 16,
                     n_queries: int = 16, seed: int = 0) -> dict:
     """Drive a single-device MemoryState and a ShardedMemory through the
     same commit stream (wraparound, duplicate rows for tie-breaks) and
-    assert bit-identical (sim, idx) — and full metadata — on every query,
-    in both mask views. Every other commit wave is routed through the
+    assert identical rows — and full metadata — on every query, in both
+    mask views, with sims that agree by :func:`sims_agree`: the two
+    stores multiply matrices of different shapes (a (Cs, E) shard
+    against the whole (C, E) ring), so a dot product may round
+    differently in its last bits. Every other commit wave is routed through the
     epoch-versioned :class:`repro.core.memory.CommitBuffer` (the shadow
     queue's deferred-commit path, staged in shuffled order + flag updates
-    with duplicate targets) so the buffer's sorted apply is pinned
-    bit-identical across both store flavours too. Returns a summary
-    dict."""
-    import numpy as np
+    with duplicate targets) so the buffer's sorted apply is pinned across
+    both store flavours too. Returns a summary dict."""
+    def same_sims(a, b):
+        assert sims_agree(a, b), (a, b)
 
     cfg = mem.MemoryConfig(capacity=capacity, embed_dim=embed_dim,
                            guide_len=guide_len)
@@ -449,17 +472,17 @@ def parity_selftest(capacity: int = 64, embed_dim: int = 16,
                                 guides_only=guides_only).device_get()
             b = sharded.query_batch(jnp.asarray(qs),
                                     guides_only=guides_only).device_get()
-            assert np.array_equal(a.sim, b.sim), (step, a.sim, b.sim)
+            same_sims(a.sim, b.sim)
             assert np.array_equal(a.meta, b.meta), (step, a.meta, b.meta)
             a1 = mem.query(single, jnp.asarray(qs[0]),
                            guides_only=guides_only).device_get()
             b1 = sharded.query(jnp.asarray(qs[0]),
                                guides_only=guides_only).device_get()
-            assert float(a1.sim) == float(b1.sim)
+            same_sims(a1.sim, b1.sim)
             assert np.array_equal(a1.meta, b1.meta)
             checks += 2 * n_queries + 2
-            # top-k: global merge of per-shard candidates must stay
-            # bit-identical to the single-device kernel, ties included
+            # top-k: the global merge of per-shard candidates must pick
+            # the single-device kernel's rows, ties included
             for k in topks:
                 ak = mem.query_topk_batch(single, jnp.asarray(qs), k,
                                           guides_only=guides_only
@@ -467,15 +490,14 @@ def parity_selftest(capacity: int = 64, embed_dim: int = 16,
                 bk = sharded.query_topk_batch(jnp.asarray(qs), k,
                                               guides_only=guides_only
                                               ).device_get()
-                assert np.array_equal(ak.sim, bk.sim), (step, k, ak.sim,
-                                                        bk.sim)
+                same_sims(ak.sim, bk.sim)
                 assert np.array_equal(ak.meta, bk.meta), (step, k)
                 a1k = mem.query_topk(single, jnp.asarray(qs[0]), k,
                                      guides_only=guides_only).device_get()
                 b1k = sharded.query_topk(jnp.asarray(qs[0]), k,
                                          guides_only=guides_only
                                          ).device_get()
-                assert np.array_equal(a1k.sim, b1k.sim), (step, k)
+                same_sims(a1k.sim, b1k.sim)
                 assert np.array_equal(a1k.meta, b1k.meta), (step, k)
                 checks += 2 * n_queries * k + 2 * k
     assert sharded.size_fast == single.size_fast
@@ -483,7 +505,7 @@ def parity_selftest(capacity: int = 64, embed_dim: int = 16,
     return {"shards": sharded.shards, "capacity": capacity,
             "checks": checks, "topk_checked": topks,
             "deferred_commit_epochs": deferred_epochs,
-            "bit_identical": True}
+            "rows_meta_identical": True, "sim_max_ulp": SIM_MAX_ULP}
 
 
 if __name__ == "__main__":

@@ -5,8 +5,9 @@ substrates: the weak/strong FMs (trained with ``repro.training``), the
 contrastive embedder, the static routers, and the evaluation pools
 ("failing samples" subsets mirroring the paper's MMLU selection, Fig. 3).
 
-Artifacts are checkpointed under ``.cache/rar_system/`` so tests,
-benchmarks and examples share one trained system.
+Artifacts are checkpointed under ``<checkout>/.cache/rar_system/``
+(:mod:`repro.cache`) so tests, benchmarks and examples of one checkout
+share one trained system.
 """
 from __future__ import annotations
 
@@ -21,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.cache import CACHE_ROOT
 from repro.configs import rar_system
 from repro.core import embedder as emb
 from repro.core.fm import FMTier
@@ -29,7 +31,7 @@ from repro.data.tasks import TaskSuite, TaskSuiteConfig
 from repro.training import (AdamWConfig, init_opt_state, load_checkpoint,
                             make_train_step, save_checkpoint)
 
-CACHE_DIR = os.environ.get("REPRO_CACHE", ".cache/rar_system")
+CACHE_DIR = str(CACHE_ROOT / "rar_system")
 
 print = functools.partial(print, flush=True)  # noqa: A001 — logs stream to files
 
@@ -88,7 +90,7 @@ def _train_lm(cfg, batch_fn, steps: int, batch_size: int, seed: int,
 
 
 def _train_embedder(ecfg, suite: TaskSuite, steps: int, batch_pairs: int,
-                    seed: int) -> Any:
+                    seed: int, log_every: int = 200) -> Any:
     key = jax.random.PRNGKey(seed + 7)
     params = emb.init_params(ecfg, key)
     opt = emb.init_opt(params)
@@ -98,7 +100,7 @@ def _train_embedder(ecfg, suite: TaskSuite, steps: int, batch_pairs: int,
         toks, sids = suite.embedder_batch(rng, batch_pairs)
         params, opt, loss = step(params, opt, jnp.asarray(toks),
                                  jnp.asarray(sids))
-        if (i + 1) % 200 == 0:
+        if log_every and (i + 1) % log_every == 0:
             print(f"  [embedder] step {i + 1}/{steps} "
                   f"ntxent={float(loss):.4f}")
     return params
@@ -131,17 +133,21 @@ def build_system(suite_cfg: TaskSuiteConfig = TaskSuiteConfig(), *,
         if verbose:
             print("[setup] training weak FM "
                   f"({rar_system.WEAK.param_count():,} params)")
+        log_every = 200 if verbose else 0
         weak_params = _train_lm(rar_system.WEAK, suite.weak_train_batch,
-                                weak_steps, batch_size, seed)
+                                weak_steps, batch_size, seed,
+                                log_every=log_every)
         if verbose:
             print("[setup] training strong FM "
                   f"({rar_system.STRONG.param_count():,} params)")
         strong_params = _train_lm(rar_system.STRONG, suite.strong_train_batch,
-                                  strong_steps, batch_size, seed + 1)
+                                  strong_steps, batch_size, seed + 1,
+                                  log_every=log_every)
         if verbose:
             print("[setup] training contrastive embedder")
         embedder_params = _train_embedder(rar_system.EMBEDDER, suite,
-                                          embedder_steps, 48, seed)
+                                          embedder_steps, 48, seed,
+                                          log_every=log_every)
         router = None  # built below, needs the weak FM
 
     embed_fn = jax.jit(partial(emb.embed, rar_system.EMBEDDER,
@@ -209,12 +215,21 @@ def failing_pool(system: TrainedSystem, domain: int, *,
     paper's data selection (weak-FM-failed subsets of MMLU)."""
     n = n or POOL_SIZES[domain]
     suite = system.suite
-    cands = suite.question_pool(domain, int(n * 2.2), seed)
-    prompts = np.stack([
-        np.asarray(suite.vocab.question(d, s, x), np.int32)
-        for d, s, x in cands])
-    ans = system.weak.answer_batch(prompts)
-    truth = np.asarray([suite.answer(s, x) for _, s, x in cands])
-    failing = [c for c, a, t in zip(cands, ans, truth) if a != t]
+    limit = len(suite.domain_skills[domain]) * suite.cfg.max_operand
+    draw = min(int(n * 2.2), limit)
+    while True:
+        cands = suite.question_pool(domain, draw, seed)
+        prompts = np.stack([
+            np.asarray(suite.vocab.question(d, s, x), np.int32)
+            for d, s, x in cands])
+        ans = system.weak.answer_batch(prompts)
+        truth = np.asarray([suite.answer(s, x) for _, s, x in cands])
+        failing = [c for c, a, t in zip(cands, ans, truth) if a != t]
+        if len(failing) >= n or draw == limit:
+            break
+        # a weak tier that fails fewer questions widens the candidate
+        # pool; one seed draws the same questions first, so a pool that
+        # was wide enough is unchanged
+        draw = min(2 * draw, limit)
     assert len(failing) >= n, (len(failing), n)
     return failing[:n]

@@ -3,9 +3,14 @@
 Selection order:
 * an explicit :func:`set_impl` override (tests) wins,
 * then ``REPRO_KERNEL_IMPL=ref|pallas|interpret`` env var,
-* otherwise: ``pallas`` on TPU backends, ``ref`` elsewhere (this CPU
-  container). ``interpret`` runs the Pallas kernel bodies in Python — used
-  by the test suite to validate the TPU kernels against the oracles.
+* otherwise: ``pallas`` on TPU backends, ``ref`` elsewhere (CPU hosts).
+  ``interpret`` runs the Pallas kernel bodies in Python — used by the test
+  suite to validate the TPU kernels against the oracles.
+
+The backend is never guessed: an error from ``jax.default_backend()``
+propagates. On a TPU backend ``interpret`` is refused, and ``ref`` (the
+jnp oracles) is reported with a warning, since either would hide the chip
+behind a slower path.
 
 The selection is resolved **once** and memoized: the old per-dispatch
 ``os.environ`` read + ``jax.default_backend()`` probe sat on the hot loop
@@ -17,6 +22,7 @@ re-resolves from the environment.
 from __future__ import annotations
 
 import os
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +40,23 @@ from repro.kernels.memory_topk import (MASK_VALID,
                                        memory_topk_batch_padded_pallas,
                                        memory_topk_padded_pallas)
 
+IMPLS = ("ref", "pallas", "interpret")
+
 _impl_cache: str | None = None
+
+
+def _checked(impl: str, source: str) -> str:
+    """Validate a requested implementation against the backend."""
+    if impl not in IMPLS:
+        raise ValueError(f"{source}={impl!r}: expected {'|'.join(IMPLS)}")
+    if impl != "pallas" and jax.default_backend() == "tpu":
+        if impl == "interpret":
+            raise ValueError(f"{source}=interpret on a TPU backend: the "
+                             f"Pallas interpreter is for CPU tests")
+        warnings.warn(f"{source}=ref on a TPU backend: serving the jnp "
+                      f"reference kernels, not the Pallas kernels",
+                      stacklevel=3)
+    return impl
 
 
 def set_impl(impl: str | None) -> None:
@@ -43,33 +65,26 @@ def set_impl(impl: str | None) -> None:
     next dispatch. The explicit hook for tests — mutating
     ``REPRO_KERNEL_IMPL`` after the first dispatch has no effect."""
     global _impl_cache
-    if impl not in (None, "ref", "pallas", "interpret"):
-        raise ValueError(f"unknown kernel impl {impl!r}")
-    _impl_cache = impl
+    _impl_cache = None if impl is None else _checked(impl, "set_impl")
 
 
-def _default_impl() -> str:
+def default_impl() -> str:
+    """The memoized implementation the dispatch serves with (resolved on
+    first call) — also what a run reports beside its device."""
     global _impl_cache
     if _impl_cache is None:
         env = os.environ.get("REPRO_KERNEL_IMPL")
         if env:
-            if env not in ("ref", "pallas", "interpret"):
-                raise ValueError(
-                    f"REPRO_KERNEL_IMPL={env!r}: expected "
-                    f"ref|pallas|interpret")
-            _impl_cache = env
+            _impl_cache = _checked(env, "REPRO_KERNEL_IMPL")
         else:
-            try:
-                platform = jax.default_backend()
-            except RuntimeError:
-                platform = "cpu"
-            _impl_cache = "pallas" if platform == "tpu" else "ref"
+            _impl_cache = ("pallas" if jax.default_backend() == "tpu"
+                           else "ref")
     return _impl_cache
 
 
 def memory_top1(mem: jax.Array, q: jax.Array, mask: jax.Array,
                 impl: str | None = None) -> tuple[jax.Array, jax.Array]:
-    impl = impl or _default_impl()
+    impl = impl or default_impl()
     if impl == "ref":
         return ref.memory_top1(mem, q, mask)
     return memory_top1_pallas(mem, q, mask, interpret=(impl == "interpret"))
@@ -79,7 +94,7 @@ def memory_top1_batch(mem: jax.Array, qs: jax.Array, mask: jax.Array,
                       impl: str | None = None
                       ) -> tuple[jax.Array, jax.Array]:
     """Multi-query top-1: qs (B, E) against mem (C, E) in one store pass."""
-    impl = impl or _default_impl()
+    impl = impl or default_impl()
     if impl == "ref":
         return ref.memory_top1_batch(mem, qs, mask)
     return memory_top1_batch_pallas(mem, qs, mask,
@@ -93,7 +108,7 @@ def memory_top1_padded(mem: jax.Array, q: jax.Array, mask: jax.Array,
     """Zero-copy top-1 over a store already in kernel layout: mem (Cp, Ep),
     mask (Cp, 1) int32 bit plane, ``required`` the bit set a row must carry
     (see ``kernels.memory_topk``). The serving dispatch path."""
-    impl = impl or _default_impl()
+    impl = impl or default_impl()
     if impl == "ref":
         return ref.memory_top1_padded(mem, q, mask, required)
     return memory_top1_padded_pallas(mem, q, mask, required=required,
@@ -105,7 +120,7 @@ def memory_top1_batch_padded(mem: jax.Array, qs: jax.Array, mask: jax.Array,
                              impl: str | None = None
                              ) -> tuple[jax.Array, jax.Array]:
     """Zero-copy multi-query top-1 over the padded kernel layout."""
-    impl = impl or _default_impl()
+    impl = impl or default_impl()
     if impl == "ref":
         return ref.memory_top1_batch_padded(mem, qs, mask, required)
     return memory_top1_batch_padded_pallas(mem, qs, mask, required=required,
@@ -119,7 +134,7 @@ def memory_topk_padded(mem: jax.Array, q: jax.Array, mask: jax.Array,
     """Zero-copy top-k over the padded kernel layout: (sims (k,),
     idx (k,)) sorted by (sim desc, row asc). The multi-guide serving
     dispatch path (``core.memory.query_topk``)."""
-    impl = impl or _default_impl()
+    impl = impl or default_impl()
     if impl == "ref":
         return ref.memory_topk_padded(mem, q, mask, k, required)
     return memory_topk_padded_pallas(mem, q, mask, k=k, required=required,
@@ -132,7 +147,7 @@ def memory_topk_batch_padded(mem: jax.Array, qs: jax.Array, mask: jax.Array,
                              ) -> tuple[jax.Array, jax.Array]:
     """Zero-copy multi-query top-k over the padded kernel layout:
     (sims (B, k), idx (B, k))."""
-    impl = impl or _default_impl()
+    impl = impl or default_impl()
     if impl == "ref":
         return ref.memory_topk_batch_padded(mem, qs, mask, k, required)
     return memory_topk_batch_padded_pallas(mem, qs, mask, k=k,
@@ -147,7 +162,7 @@ def ivf_route_padded(cent: jax.Array, q: jax.Array, cmask: jax.Array,
     """Level-1 centroid route over the padded centroid plane:
     (scores (n_probe,), cids (n_probe,)) sorted by (score desc, row asc).
     The IVF dispatch path (``core.memory_ivf``)."""
-    impl = impl or _default_impl()
+    impl = impl or default_impl()
     if impl == "ref":
         return ref.ivf_route_padded(cent, q, cmask, n_probe, required)
     return ivf_route_padded_pallas(cent, q, cmask, n_probe=n_probe,
@@ -161,7 +176,7 @@ def ivf_route_batch_padded(cent: jax.Array, qs: jax.Array, cmask: jax.Array,
                            ) -> tuple[jax.Array, jax.Array]:
     """Multi-query level-1 centroid route: (scores (B, n_probe),
     cids (B, n_probe))."""
-    impl = impl or _default_impl()
+    impl = impl or default_impl()
     if impl == "ref":
         return ref.ivf_route_batch_padded(cent, qs, cmask, n_probe, required)
     return ivf_route_batch_padded_pallas(cent, qs, cmask, n_probe=n_probe,
@@ -171,7 +186,7 @@ def ivf_route_batch_padded(cent: jax.Array, qs: jax.Array, cmask: jax.Array,
 
 def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
                     impl: str | None = None):
-    impl = impl or _default_impl()
+    impl = impl or default_impl()
     if impl == "ref":
         return ref.flash_attention(q, k, v, causal=causal, window=window,
                                    scale=scale)
@@ -182,7 +197,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
 
 def decode_attention(q, k, v, cache_len, *, window=0, scale=None,
                      impl: str | None = None):
-    impl = impl or _default_impl()
+    impl = impl or default_impl()
     if impl == "ref":
         return ref.decode_attention(q, k, v, cache_len, window=window,
                                     scale=scale)

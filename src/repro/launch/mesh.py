@@ -11,6 +11,7 @@ Target hardware: TPU v5e, 256 chips/pod (16×16), 2 pods.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # v5e hardware constants used by the roofline (see EXPERIMENTS.md §Roofline)
 PEAK_FLOPS = 197e12          # bf16 per chip
@@ -21,9 +22,10 @@ ICI_BW = 50e9                # bytes/s per link
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2):
     """Small mesh over however many devices exist (tests)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -1,10 +1,12 @@
 """Serving launcher — run the RAR layered system over a request stream.
 
 This is the paper's deployment shape: a weak tier + strong tier behind the
-adaptive router, serving batched requests. On CPU it runs the trained
-synthetic-suite system end-to-end; production zoo archs slot in as tiers
-via --weak-arch/--strong-arch in dry-run form (see repro.launch.dryrun for
-the distributed serve_step itself).
+adaptive router, serving batched requests. It trains (or loads from
+``<checkout>/.cache/rar_system``) the synthetic-suite system — the
+``rar-weak``/``rar-strong`` tiers and the embedder of
+``repro.configs.rar_system`` — and serves it end to end, on the CPU or on
+one accelerator. The tiers are fixed; the zoo architectures' distributed
+serve step is compiled by ``repro.launch.dryrun``, not served here.
 
 Recovery plane: the launcher exposes the fault-tolerance stack of
 ``repro.serving`` — tier-call retries with exponential backoff
@@ -34,12 +36,14 @@ import time
 
 import numpy as np
 
+from repro.cache import enable_compile_cache
 from repro.configs.rar_system import make_rar_config
 from repro.experiments.setup import build_system, failing_pool
 from repro.experiments.stages import run_rar_experiment
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         description="Serve a request stream through the RAR layered "
                     "system (weak/strong tiers + adaptive router + "
@@ -69,7 +73,9 @@ def main() -> None:
                          "— a crashed or SIGKILL'd worker is detected "
                          "by heartbeat leases, respawned, and its in-"
                          "flight microbatches redispatch byte-"
-                         "identically (requires --router oracle)")
+                         "identically (requires --router oracle; CPU "
+                         "backend only, since a chip belongs to one "
+                         "process)")
     ap.add_argument("--router", default="oracle",
                     choices=["oracle", "learned"])
     ap.add_argument("--arrival-pattern", default="closed",

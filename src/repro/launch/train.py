@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.cache import enable_compile_cache
 from repro.models import init_params
 from repro.training import AdamWConfig, init_opt_state, make_train_step
 from repro.training.checkpoint import save_checkpoint
@@ -76,6 +77,7 @@ def train(arch: str, *, smoke: bool, steps: int, batch: int, seq: int,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

@@ -423,6 +423,15 @@ class ProcessServingFabric(ServingFabric):
     def __init__(self, replica_factory, cfg=None, *, workers: int = 1,
                  fault_plan=None, lease_interval: float = 0.25,
                  lease_timeout: float = 5.0, start_method: str = "spawn"):
+        if jax.default_backend() != "cpu":
+            # a chip belongs to one process: the parent's learn plane
+            # holds it, so a worker that builds tiers of its own would
+            # fail or hang waiting for it
+            raise RuntimeError(
+                f"ProcessServingFabric runs on the CPU backend only, not "
+                f"{jax.default_backend()!r}: on an accelerator serve "
+                f"replicas as threads of one process (ServingFabric, "
+                f"--transport thread)")
         if workers < 1:
             raise ValueError(f"workers={workers} must be >= 1")
         if lease_timeout <= lease_interval:

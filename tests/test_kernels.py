@@ -476,15 +476,51 @@ def test_impl_selection_memoized_with_override(monkeypatch):
     try:
         monkeypatch.setenv("REPRO_KERNEL_IMPL", "interpret")
         ops.set_impl(None)
-        assert ops._default_impl() == "interpret"
+        assert ops.default_impl() == "interpret"
         # memoized: flipping the env after first resolution has no effect
         monkeypatch.setenv("REPRO_KERNEL_IMPL", "ref")
-        assert ops._default_impl() == "interpret"
+        assert ops.default_impl() == "interpret"
         # the override hook wins immediately
         ops.set_impl("ref")
-        assert ops._default_impl() == "ref"
+        assert ops.default_impl() == "ref"
         with pytest.raises(ValueError):
             ops.set_impl("bogus")
+    finally:
+        ops._impl_cache = saved
+
+
+@pytest.mark.parametrize("case", ["default", "interpret", "ref", "error"])
+def test_impl_selection_on_tpu_backend(monkeypatch, case):
+    """On a TPU backend the dispatch serves the Pallas kernels: the
+    interpreter is refused, the jnp reference is reported, and a backend
+    error propagates instead of turning into the CPU path."""
+    from repro.kernels import ops
+
+    saved = ops._impl_cache
+    monkeypatch.delenv("REPRO_KERNEL_IMPL", raising=False)
+    if case == "error":
+        def no_backend():
+            raise RuntimeError("no backend")
+        monkeypatch.setattr(ops.jax, "default_backend", no_backend)
+    else:
+        monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    try:
+        ops.set_impl(None)
+        if case == "default":
+            assert ops.default_impl() == "pallas"
+        elif case == "interpret":
+            with pytest.raises(ValueError, match="TPU"):
+                ops.set_impl("interpret")
+            monkeypatch.setenv("REPRO_KERNEL_IMPL", "interpret")
+            with pytest.raises(ValueError, match="TPU"):
+                ops.default_impl()
+        elif case == "ref":
+            monkeypatch.setenv("REPRO_KERNEL_IMPL", "ref")
+            with pytest.warns(UserWarning, match="reference kernels"):
+                assert ops.default_impl() == "ref"
+        else:
+            with pytest.raises(RuntimeError, match="no backend"):
+                ops.default_impl()
     finally:
         ops._impl_cache = saved
 

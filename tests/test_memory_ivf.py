@@ -378,6 +378,26 @@ def test_ivf_grow_requeries_exact(rng):
     _assert_matches_exact(ivf, qs, 4, batch=True)
 
 
+def test_ivf_grow_widens_default_buckets():
+    """A default bucket width follows the grown capacity: with the width
+    of the old ring, this seed's skewed cluster overflowed its bucket
+    after the grow and probing every cluster missed rows."""
+    rng = np.random.default_rng(56)
+    C = 64
+    store = mem.init_memory(mem.MemoryConfig(capacity=C, embed_dim=E,
+                                             guide_len=G))
+    ivf = IVFMemory(store, clusters=8, probes=8)
+    protos = _protos(rng, 8)
+    _fill(ivf, rng, _clustered(rng, protos, C + 24))
+    cap = ivf.bucket_cap
+    ivf.grow(2 * C)
+    assert ivf.bucket_cap == 2 * cap
+    _fill(ivf, rng, _clustered(rng, protos, 32))
+    assert ivf.stats()["bucket_evictions"] == 0
+    qs = jnp.asarray(_clustered(rng, protos, 4))
+    _assert_matches_exact(ivf, qs, 4, batch=True)
+
+
 # ---------------------------------------------------------------------------
 # Host-offload tiering
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
 """Sharded memory store: single-shard behaviour in-process, multi-shard
-bit-identical parity (incl. tie-breaks) via a subprocess with forced host
+parity (identical rows incl. tie-breaks, sims within a few ulp) via a subprocess with forced host
 placeholder devices (XLA device count must be set before jax initializes),
 and the microbatched controller serving against the sharded store."""
 import json
@@ -93,8 +93,9 @@ def test_capacity_must_divide_shards():
 
 
 def test_multi_shard_parity_subprocess():
-    """4 forced host devices: sharded (sim, idx) — and the full packed
-    metadata — bit-identical to single-device, tie-breaks included."""
+    """4 forced host devices: sharded rows — and the full packed
+    metadata — identical to single-device, tie-breaks included, and sims
+    within a few ulp (the two stores multiply different matrix shapes)."""
     flags = (os.environ.get("XLA_FLAGS", "")
              + " --xla_force_host_platform_device_count=4").strip()
     env = dict(os.environ,
@@ -106,7 +107,8 @@ def test_multi_shard_parity_subprocess():
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
     report = json.loads(r.stdout.strip().splitlines()[-1])
     assert report["shards"] == 4
-    assert report["bit_identical"] is True
+    assert report["rows_meta_identical"] is True
+    assert report["sim_max_ulp"] <= 4
     assert report["checks"] > 0
     # the selftest must have exercised the top-k merge across shards
     # (global top-k == single-device top-k on the same ring, ties incl.)

@@ -460,6 +460,20 @@ def test_constructor_validation():
                              lease_interval=1.0, lease_timeout=0.5)
 
 
+def test_refuses_accelerator_backend(monkeypatch):
+    """A chip belongs to one process, so on an accelerator the process
+    transport refuses to start (pointing at the thread fabric) before it
+    builds a tier or spawns a worker."""
+    import jax
+
+    def factory():
+        raise AssertionError("no tier may be built")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="thread"):
+        ProcessServingFabric(factory, make_cfg(), workers=1)
+
+
 # ---------------------------------------------------------------------------
 # Parent learn-plane drain cadence driven by worker commit-epoch lag
 # ---------------------------------------------------------------------------
