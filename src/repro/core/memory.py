@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+import time
 from functools import partial
 
 import jax
@@ -56,6 +57,7 @@ from repro.kernels import ops as kops
 from repro.kernels.memory_topk import (DEFAULT_BLOCK_C, MASK_GUIDE,
                                        MASK_VALID, padded_lanes,
                                        padded_rows)
+from repro.serving.metrics import count_syncs, span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -903,37 +905,51 @@ class CommitStream:
         journal, the epoch is made durable (write-ahead) before the
         apply; the ``commit_apply`` fault site fires between the two —
         the kill-mid-epoch point the recovery property tests. Returns
-        the new store."""
+        the new store.
+
+        A non-empty epoch is one ``rar.commit`` span; its seconds go to
+        the ``commit/apply_seconds`` histogram and the store pointer's
+        read in :meth:`CommitBuffer.apply_ops` counts as a
+        ``host/syncs/commit`` fetch."""
         with self.lock:
             if not self.buffer.pending:
                 return state
-            records, soft_clears, touches = self.buffer.take_ops()
-            epoch = self.buffer.epoch + 1
-            manifest = None
-            if self.journal is not None:
-                if self.state_provider is not None:
-                    manifest = self.state_provider()
-                self.journal.log_epoch(epoch, records, soft_clears,
-                                       touches, manifest)
-            if self.fault_plan is not None:
-                self.fault_plan.fire("commit_apply", epoch=epoch)
-            state, n = self.buffer.apply_ops(state, records, soft_clears,
-                                             touches)
-            self.commits += n
-            for v in self._views:
-                v.memory = state
-                v.commit_epoch_seen = self.buffer.epoch
-            if self.ops_listener is not None:
-                self.ops_listener(epoch, records, soft_clears, touches,
-                                  n)
+            t0 = time.monotonic()
+            with span("rar.commit"):
+                state = self._apply_locked(state)
             if self.metrics is not None:
-                with self.metrics.lock:
-                    self.metrics.counter("commit/epochs_applied").inc()
-                    self.metrics.counter("commit/entries_applied").inc(n)
-                    self.metrics.gauge("commit/epoch").set(
-                        self.buffer.epoch)
-            if self.journal is not None:
-                self.journal.maybe_snapshot(state, self.buffer, manifest)
+                self.metrics.histogram("commit/apply_seconds").observe(
+                    time.monotonic() - t0)
+        return state
+
+    def _apply_locked(self, state):
+        records, soft_clears, touches = self.buffer.take_ops()
+        epoch = self.buffer.epoch + 1
+        manifest = None
+        if self.journal is not None:
+            if self.state_provider is not None:
+                manifest = self.state_provider()
+            self.journal.log_epoch(epoch, records, soft_clears, touches,
+                                   manifest)
+        if self.fault_plan is not None:
+            self.fault_plan.fire("commit_apply", epoch=epoch)
+        if isinstance(state.ptr, jax.Array):
+            count_syncs(self.metrics, "commit")
+        state, n = self.buffer.apply_ops(state, records, soft_clears,
+                                         touches)
+        self.commits += n
+        for v in self._views:
+            v.memory = state
+            v.commit_epoch_seen = self.buffer.epoch
+        if self.ops_listener is not None:
+            self.ops_listener(epoch, records, soft_clears, touches, n)
+        if self.metrics is not None:
+            with self.metrics.lock:
+                self.metrics.counter("commit/epochs_applied").inc()
+                self.metrics.counter("commit/entries_applied").inc(n)
+                self.metrics.gauge("commit/epoch").set(self.buffer.epoch)
+        if self.journal is not None:
+            self.journal.maybe_snapshot(state, self.buffer, manifest)
         return state
 
     def grow(self, state, new_capacity: int):

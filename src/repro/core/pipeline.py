@@ -74,6 +74,7 @@ from repro.core import memory as mem
 from repro.core import shadow as shq
 from repro.core.fm import TierUnavailableError
 from repro.core.rar import RAR, Outcome, select_guides, splice_guides
+from repro.serving.metrics import count_syncs, span, thread_syncs
 
 
 def _answers(tier, prompts: list[np.ndarray]) -> np.ndarray:
@@ -203,9 +204,11 @@ class MicrobatchRAR(RAR):
         """One batched memory read: top-``retrieval_k`` entries per
         query, fused epilogue, one host transfer (the batched analog of
         ``RAR._lookup``)."""
-        return mem.query_topk_batch(self.memory, jnp.asarray(embs),
-                                    self.cfg.retrieval_k,
-                                    guides_only=guides_only).device_get()
+        q = mem.query_topk_batch(self.memory, jnp.asarray(embs),
+                                 self.cfg.retrieval_k,
+                                 guides_only=guides_only).device_get()
+        count_syncs(self.metrics_registry, "lookup")
+        return q
 
     def _snapshot_lookup(self, embs, guides_only: bool = False
                          ) -> mem.TopKResult:
@@ -213,7 +216,7 @@ class MicrobatchRAR(RAR):
         commit apply and this snapshot serialize, so the result always
         reflects a whole number of drain epochs (no torn multi-field
         reads on the mutable sharded store)."""
-        with self.shadow.store_lock:
+        with span("rar.lookup"), self.shadow.store_lock:
             return self._lookup_batch(embs, guides_only=guides_only)
 
     # ------------------------------------------------------------------
@@ -223,14 +226,30 @@ class MicrobatchRAR(RAR):
                       guide_requests: list[np.ndarray],
                       keys: list | None = None,
                       embs: np.ndarray | None = None,
-                      nows: list[int] | None = None) -> list[Outcome]:
+                      nows: list[int] | None = None,
+                      tags: dict | None = None) -> list[Outcome]:
         """Serve one microbatch. ``prompts[i]``/``guide_requests[i]``/
         ``keys[i]`` mirror the arguments of ``RAR.process``; ``embs`` may
         carry precomputed request embeddings (B, E). ``nows`` may carry
         pre-allocated logical time stamps (the process fabric allocates
         them from the parent's shared clock at dispatch, so a redispatch
         after a worker death reuses the *same* stamps — the byte-identity
-        anchor)."""
+        anchor).
+
+        The microbatch is one ``rar.batch`` span. ``tags`` ride on it as
+        trace metadata (the fabric passes its ticket's ``batch`` id and
+        the ``wait_us`` the batch queued for its replica), and it closes
+        with ``syncs``, the blocking device→host fetches this thread made
+        inside it."""
+        syncs0 = thread_syncs()
+        sp = span("rar.batch", **(tags or {}))
+        with sp:
+            outcomes = self._serve_batch(prompts, guide_requests, keys,
+                                         embs, nows)
+            sp.set_metadata(syncs=thread_syncs() - syncs0)
+        return outcomes
+
+    def _serve_batch(self, prompts, guide_requests, keys, embs, nows):
         B = len(prompts)
         if B > self.cfg.memory.capacity:
             # every request may record one entry; reject before any FM
@@ -247,7 +266,10 @@ class MicrobatchRAR(RAR):
             self.now = max(self.now, max(nows))   # keep the mirror sane
 
         if embs is None:
-            embs = np.stack([np.asarray(self.embed_fn(p)) for p in prompts])
+            with span("rar.embed"):
+                embs = np.stack([np.asarray(self.embed_fn(p))
+                                 for p in prompts])
+            count_syncs(self.metrics_registry, "embed", B)
         else:
             embs = np.asarray(embs)
 
@@ -259,7 +281,7 @@ class MicrobatchRAR(RAR):
         # pointer is captured under the same lock: re-probe flag updates
         # staged later carry it so the commit buffer can drop them if an
         # intervening drain epoch evicts the target slot.
-        with self.shadow.store_lock:
+        with span("rar.lookup"), self.shadow.store_lock:
             q = self._lookup_batch(embs)
             ptr_snap = self._ptr_base + self.commit_stream.commits
 
@@ -267,10 +289,11 @@ class MicrobatchRAR(RAR):
         # the same code path the sequential controller runs per request).
         # The strong tier's breaker feeds in as a routing input: while it
         # is open, hard/shadow requests land in the degraded groups.
-        part = decisions.partition(
-            q, nows, self.cfg,
-            lambda i: self.route_weak_fn(np.asarray(embs[i]), keys[i]),
-            strong_ok=self._strong_ok())
+        with span("rar.decide"):
+            part = decisions.partition(
+                q, nows, self.cfg,
+                lambda i: self.route_weak_fn(np.asarray(embs[i]), keys[i]),
+                strong_ok=self._strong_ok())
         outcomes: list[Outcome | None] = [None] * B
 
         # ---- phase 3: one strong sweep (memory_hard + shadow requests).
@@ -281,15 +304,19 @@ class MicrobatchRAR(RAR):
         # the batch degrades mid-flight — no errored requests.
         items: list[shq.ShadowItem] = []
         strong_reqs = part.hard + [i for i, _ in part.shadow]
+        strong_ans = None
         if strong_reqs:
             try:
-                strong_ans = _answers(self.strong, [prompts[i]
-                                                    for i in strong_reqs])
+                with span("rar.strong"):
+                    strong_ans = _answers(self.strong, [prompts[i]
+                                                        for i in strong_reqs])
             except TierUnavailableError:
                 part.hard_degraded += part.hard
                 part.deferred += part.shadow
                 part.hard, part.shadow = [], []
-            else:
+
+        with span("rar.decide"):
+            if strong_ans is not None:
                 for i, a in zip(part.hard, strong_ans):
                     outcomes[i] = Outcome(int(a), "strong", 1,
                                           "memory_hard")
@@ -304,62 +331,64 @@ class MicrobatchRAR(RAR):
                         outcome=out, reprobe_index=reprobe,
                         ptr_snapshot=ptr_snap))
 
-        # ---- phase 4: one weak *serve* sweep (guided hits, bare hits,
-        # router passthroughs). Shadow weak probes are not serve work and
-        # run in the drain instead.
-        weak_prompts: list[np.ndarray] = []
-        weak_tags: list[tuple[str, int]] = []
-        for i in part.guide:
-            weak_prompts.append(splice_guides(
-                prompts[i], select_guides(q.sim[i], q.has_guide[i],
-                                          q.guide[i],
-                                          self.cfg.sim_threshold,
-                                          self.cfg.max_guides)))
-            weak_tags.append(("guide", i))
-        for i in part.skill:
-            weak_prompts.append(prompts[i])
-            weak_tags.append(("skill", i))
-        for i in part.router:
-            weak_prompts.append(prompts[i])
-            weak_tags.append(("router", i))
-        # degraded groups ride the same weak sweep (appended after the
-        # regular groups, so non-degraded batches are byte-identical to
-        # the pre-resilience sweep order)
-        for i in part.hard_degraded:
-            weak_prompts.append(prompts[i])
-            weak_tags.append(("hard_degraded", i))
-        deferred_reprobe = dict(part.deferred)
-        for i, _ in part.deferred:
-            weak_prompts.append(prompts[i])
-            weak_tags.append(("deferred", i))
+            # ---- phase 4: one weak *serve* sweep (guided hits, bare
+            # hits, router passthroughs). Shadow weak probes are not
+            # serve work and run in the drain instead.
+            weak_prompts: list[np.ndarray] = []
+            weak_tags: list[tuple[str, int]] = []
+            for i in part.guide:
+                weak_prompts.append(splice_guides(
+                    prompts[i], select_guides(q.sim[i], q.has_guide[i],
+                                              q.guide[i],
+                                              self.cfg.sim_threshold,
+                                              self.cfg.max_guides)))
+                weak_tags.append(("guide", i))
+            for i in part.skill:
+                weak_prompts.append(prompts[i])
+                weak_tags.append(("skill", i))
+            for i in part.router:
+                weak_prompts.append(prompts[i])
+                weak_tags.append(("router", i))
+            # degraded groups ride the same weak sweep (appended after
+            # the regular groups, so non-degraded batches are
+            # byte-identical to the pre-resilience sweep order)
+            for i in part.hard_degraded:
+                weak_prompts.append(prompts[i])
+                weak_tags.append(("hard_degraded", i))
+            deferred_reprobe = dict(part.deferred)
+            for i, _ in part.deferred:
+                weak_prompts.append(prompts[i])
+                weak_tags.append(("deferred", i))
         if weak_prompts:
-            weak_ans = _answers(self.weak, weak_prompts)
-            for (tag, i), a in zip(weak_tags, weak_ans):
-                a = int(a)
-                if tag == "guide":
-                    outcomes[i] = Outcome(a, "weak", 0, "memory_guide",
-                                          guide_source="memory")
-                elif tag == "skill":
-                    outcomes[i] = Outcome(a, "weak", 0, "memory_skill")
-                elif tag == "hard_degraded":
-                    outcomes[i] = Outcome(a, "weak", 0,
-                                          "memory_hard_degraded")
-                elif tag == "deferred":
-                    # weak serves now; the suppressed strong probe parks
-                    # until the breaker closes (replay_deferred)
-                    out = Outcome(a, "weak", 0, "shadow_deferred")
-                    outcomes[i] = out
-                    self.deferred_probes.append(shq.ShadowItem(
-                        seq=self.shadow.next_seq(), now=nows[i],
-                        prompt=prompts[i],
-                        guide_request=guide_requests[i],
-                        emb=np.asarray(embs[i]), strong_ans=-1,
-                        outcome=out,
-                        reprobe_index=deferred_reprobe[i],
-                        ptr_snapshot=ptr_snap, strong_calls=0))
-                    self.probes_deferred += 1
-                else:
-                    outcomes[i] = Outcome(a, "weak", 0, "router_weak")
+            with span("rar.weak"):
+                weak_ans = _answers(self.weak, weak_prompts)
+            with span("rar.decide"):
+                for (tag, i), a in zip(weak_tags, weak_ans):
+                    a = int(a)
+                    if tag == "guide":
+                        outcomes[i] = Outcome(a, "weak", 0, "memory_guide",
+                                              guide_source="memory")
+                    elif tag == "skill":
+                        outcomes[i] = Outcome(a, "weak", 0, "memory_skill")
+                    elif tag == "hard_degraded":
+                        outcomes[i] = Outcome(a, "weak", 0,
+                                              "memory_hard_degraded")
+                    elif tag == "deferred":
+                        # weak serves now; the suppressed strong probe
+                        # parks until the breaker closes (replay_deferred)
+                        out = Outcome(a, "weak", 0, "shadow_deferred")
+                        outcomes[i] = out
+                        self.deferred_probes.append(shq.ShadowItem(
+                            seq=self.shadow.next_seq(), now=nows[i],
+                            prompt=prompts[i],
+                            guide_request=guide_requests[i],
+                            emb=np.asarray(embs[i]), strong_ans=-1,
+                            outcome=out,
+                            reprobe_index=deferred_reprobe[i],
+                            ptr_snapshot=ptr_snap, strong_calls=0))
+                        self.probes_deferred += 1
+                    else:
+                        outcomes[i] = Outcome(a, "weak", 0, "router_weak")
 
         # ---- phase 5: hand the shadow work to the queue. Inline mode
         # drains here; deferred/async return after the serve sweeps alone.
@@ -391,7 +420,8 @@ class MicrobatchRAR(RAR):
                     self.shadow.reclaimed_weak_calls,
                     self.shadow.reclaimed_strong_calls)
         try:
-            self._drain_shadow_epoch(items)
+            with span("rar.drain"):
+                self._drain_shadow_epoch(items)
         except BaseException:
             buf.rollback(mark)
             for it, (sc, case, osc, gs) in zip(items, saved):
@@ -461,22 +491,82 @@ class MicrobatchRAR(RAR):
                 m.outcome.guide_source = res.guide_source
 
         # ---- sweep 1: weak-alone probes (Case 1)
-        weak_ans = _answers(self.weak, [it.prompt for it in leaders])
-        probe_calls += len(leaders)
         pending: list[shq.ShadowItem] = []
-        for it, a in zip(leaders, weak_ans):
-            if self.aligned_fn(int(a), it.strong_ans):
-                settle(it, "case1", empty_guide)
-            else:
-                pending.append(it)
+        with span("rar.drain.weak_probe"):
+            weak_ans = _answers(self.weak, [it.prompt for it in leaders])
+            probe_calls += len(leaders)
+            with span("rar.decide"):
+                for it, a in zip(leaders, weak_ans):
+                    if self.aligned_fn(int(a), it.strong_ans):
+                        settle(it, "case1", empty_guide)
+                    else:
+                        pending.append(it)
 
         # ---- sweep 2: guide-from-memory probes (Case 2a), against the
         # store snapshot at drain start
         still: list[shq.ShadowItem] = []
         if pending:
-            gq = self._snapshot_lookup(
-                np.stack([it.emb for it in pending]), guides_only=True)
-            probes, probe_items, probe_guides = [], [], []
+            with span("rar.drain.guide_probe"):
+                self._guide_probe_sweep(pending, still, probed_2a, settle)
+            probe_calls += len(probed_2a)
+
+        # ---- sweep 3: fresh guides (one strong generate_guides sweep)
+        # + guided weak probes (Case 2b)
+        failed: list[shq.ShadowItem] = []
+        if still and self.cfg.allow_fresh_guides:
+            with span("rar.drain.fresh_guide"):
+                try:
+                    fresh = _guides(self.strong,
+                                    [it.guide_request for it in still],
+                                    self.cfg.memory.guide_len)
+                except TierUnavailableError:
+                    # strong tier down mid-drain: no fresh guide
+                    # available — the items resolve as Case 3, exactly
+                    # like the sequential probe's degraded case-2b leg
+                    # (no strong call charged)
+                    failed = still
+                else:
+                    for it in still:
+                        it.strong_calls += 1
+                        fresh_ran.add(it.seq)
+                    probe_calls += len(still)  # strong guide generations
+                    probe_ans = _answers(self.weak,
+                                         [splice_guides(it.prompt, [g])
+                                          for it, g in zip(still, fresh)])
+                    probe_calls += len(still)  # guided weak probes
+                    with span("rar.decide"):
+                        for it, g, a in zip(still, fresh, probe_ans):
+                            if self.aligned_fn(int(a), it.strong_ans):
+                                settle(it, "case2b", g)
+                            else:
+                                failed.append(it)
+        else:
+            failed = still
+
+        if failed:
+            with span("rar.decide"):
+                for it in failed:                      # Case 3
+                    settle(it, "case3", empty_guide)
+
+        # ---- one epoch apply through the commit stream: adds first
+        # (FIFO order by logical time, matching the sequential
+        # add-then-flag order), then re-probe flag updates; flag updates
+        # whose pre-epoch slot this epoch's scatter just evicted are
+        # dropped (CommitBuffer contract). The apply, the commit-counter
+        # bump and the broadcast to every subscribed replica view happen
+        # atomically under the stream's store lock.
+        self.shadow.note_probe_calls(probe_calls)
+        self.memory = self.commit_stream.apply(self.memory)
+
+    def _guide_probe_sweep(self, pending, still, probed_2a, settle) -> None:
+        """Sweep 2 of a drain epoch (Case 2a): probe the weak tier with
+        guides read from the store snapshot. Items it does not resolve
+        go to ``still`` in seq order; the leaders it probed join
+        ``probed_2a``."""
+        gq = self._snapshot_lookup(np.stack([it.emb for it in pending]),
+                                   guides_only=True)
+        probes, probe_items, probe_guides = [], [], []
+        with span("rar.decide"):
             for j, it in enumerate(pending):
                 if decisions.wants_guide_probe(float(gq.sim[j, 0]),
                                                self.cfg):
@@ -493,56 +583,12 @@ class MicrobatchRAR(RAR):
                     probe_guides.append(guides[0])
                 else:
                     still.append(it)
-            if probes:
-                probe_ans = _answers(self.weak, probes)
-                probe_calls += len(probes)
+        if probes:
+            probe_ans = _answers(self.weak, probes)
+            with span("rar.decide"):
                 for it, g, a in zip(probe_items, probe_guides, probe_ans):
                     if self.aligned_fn(int(a), it.strong_ans):
                         settle(it, "case2a", g)
                     else:
                         still.append(it)
-            still.sort(key=lambda it: it.seq)
-
-        # ---- sweep 3: fresh guides (one strong generate_guides sweep)
-        # + guided weak probes (Case 2b)
-        failed: list[shq.ShadowItem] = []
-        if still and self.cfg.allow_fresh_guides:
-            try:
-                fresh = _guides(self.strong,
-                                [it.guide_request for it in still],
-                                self.cfg.memory.guide_len)
-            except TierUnavailableError:
-                # strong tier down mid-drain: no fresh guide available —
-                # the items resolve as Case 3, exactly like the
-                # sequential probe's degraded case-2b leg (no strong
-                # call charged)
-                failed = still
-            else:
-                for it in still:
-                    it.strong_calls += 1
-                    fresh_ran.add(it.seq)
-                probe_calls += len(still)      # strong guide generations
-                probe_ans = _answers(self.weak,
-                                     [splice_guides(it.prompt, [g])
-                                      for it, g in zip(still, fresh)])
-                probe_calls += len(still)      # guided weak probes
-                for it, g, a in zip(still, fresh, probe_ans):
-                    if self.aligned_fn(int(a), it.strong_ans):
-                        settle(it, "case2b", g)
-                    else:
-                        failed.append(it)
-        else:
-            failed = still
-
-        for it in failed:                              # Case 3
-            settle(it, "case3", empty_guide)
-
-        # ---- one epoch apply through the commit stream: adds first
-        # (FIFO order by logical time, matching the sequential
-        # add-then-flag order), then re-probe flag updates; flag updates
-        # whose pre-epoch slot this epoch's scatter just evicted are
-        # dropped (CommitBuffer contract). The apply, the commit-counter
-        # bump and the broadcast to every subscribed replica view happen
-        # atomically under the stream's store lock.
-        self.shadow.note_probe_calls(probe_calls)
-        self.memory = self.commit_stream.apply(self.memory)
+        still.sort(key=lambda it: it.seq)

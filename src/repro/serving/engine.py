@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.models import decode_step, prefill
 from repro.models.config import ModelConfig
+from repro.serving.metrics import count_syncs, span
 
 
 def bucket_batch(n: int) -> int:
@@ -73,6 +74,9 @@ class ServingEngine:
         self.tokens_processed = 0
         self.jit_hits = 0       # generate() reused a compiled variant
         self.jit_misses = 0     # generate() traced + compiled a new one
+        # registry for the ``host/syncs/engine`` counter (the fabric sets
+        # its own; None counts on the thread's tally alone)
+        self.metrics = None
         # the async shadow drainer serves sweeps on its own thread while
         # the serve plane keeps generating — the jit-cache dict and the
         # cost counters (non-atomic read-modify-writes) need a lock to
@@ -109,6 +113,9 @@ class ServingEngine:
         calls). ``calls`` stays logical (real requests only) while
         ``tokens_processed``/``flops_spent`` stay physical — padding rows
         do consume compute and are deliberately included there.
+        Each group is one ``rar.engine.launch`` span (stack, transfer,
+        dispatch) and one ``rar.engine.fetch`` span (the blocking wait for
+        its tokens, a ``host/syncs/engine`` count).
         Returns (N, max_new) int32 in input order."""
         by_len: dict[int, list[int]] = {}
         for i, p in enumerate(prompts):
@@ -117,19 +124,26 @@ class ServingEngine:
         for L, idxs in sorted(by_len.items()):
             B = len(idxs)
             Bp = bucket_batch(B)
-            batch = np.stack([np.asarray(prompts[i], np.int32)
-                              for i in idxs] +
-                             [np.asarray(prompts[idxs[0]], np.int32)] *
-                             (Bp - B))
-            got = np.asarray(self.generate({"tokens": jnp.asarray(batch)},
-                                           max_new))
+            with span("rar.engine.launch"):
+                batch = np.stack([np.asarray(prompts[i], np.int32)
+                                  for i in idxs] +
+                                 [np.asarray(prompts[idxs[0]], np.int32)] *
+                                 (Bp - B))
+                pending = self.generate({"tokens": jnp.asarray(batch)},
+                                        max_new)
+            with span("rar.engine.fetch"):
+                got = np.asarray(pending)
+            count_syncs(self.metrics, "engine")
             self._bill(-(Bp - B), 0)      # padding rows are not requests
             out[idxs] = got[:B]
         return out
 
     @property
     def flops_spent(self) -> float:
-        return self.tokens_processed * self.cfg.flops_per_token()
+        """Forward-pass FLOPs of every token processed: 2 N per token
+        (N active parameters; serving runs no backward pass, so not the
+        training count ``cfg.flops_per_token()`` = 6 N)."""
+        return self.tokens_processed * 2.0 * self.cfg.active_param_count()
 
     def stats(self) -> dict:
         """Consistent host-side counter snapshot (one lock hold, no

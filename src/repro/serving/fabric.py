@@ -162,8 +162,17 @@ class Ticket:
     ``redispatches`` counts supervisor re-runs after a replica crash
     (``replica`` is rewritten to the surviving replica each time); a
     timed-out :meth:`wait` leaves the ticket fully waitable — the batch
-    is still in flight, not abandoned."""
+    is still in flight, not abandoned.
+
+    ``id`` numbers the fabric's microbatches in submission order; the
+    replica's ``rar.batch`` span carries it. ``submitted`` and
+    ``started`` are ``time.monotonic()`` stamps of the submit and of the
+    worker's dequeue: ``started - submitted`` is the batch's wait in its
+    replica's FIFO."""
     replica: int
+    id: int = -1
+    submitted: float | None = None
+    started: float | None = None
     outcomes: list[Outcome] | None = None
     error: BaseException | None = None
     redispatches: int = 0
@@ -222,6 +231,10 @@ class ServingFabric:
         # snapshots everything consistently
         self.metrics_registry = MetricsRegistry()
         self.commit_stream.metrics = self.metrics_registry
+        for tier in (weak, strong):
+            engine = getattr(tier, "engine", None)
+            if getattr(engine, "metrics", False) is None:
+                engine.metrics = self.metrics_registry
         # global adaptive cadence: one shared policy across every
         # replica's queue (None unless shadow_mode == "adaptive")
         self.drain_policy = (AdaptiveDrainPolicy()
@@ -264,6 +277,7 @@ class ServingFabric:
         # replaces exactly its slot
         self._threads: list[threading.Thread | None] = []
         self._tickets: list[Ticket] = []
+        self._next_ticket = 0
         #: supervision state, one entry per replica (∈ :data:`HEALTH`)
         self.health: list[str] = ["healthy"] * replicas
         self.deaths = 0        # worker threads lost to a ReplicaCrash
@@ -392,6 +406,10 @@ class ServingFabric:
             if task is None:
                 return
             ticket = task[0]
+            ticket.started = time.monotonic()
+            wait_s = ticket.started - ticket.submitted
+            self.metrics_registry.histogram(
+                f"replica{i}/fabric/wait_seconds").observe(wait_s)
             try:
                 if self.fault_plan is not None:
                     # the injection point is BEFORE the replica touches
@@ -400,7 +418,9 @@ class ServingFabric:
                     # byte-identical to a no-fault run
                     self.fault_plan.fire("replica_serve", replica=i)
                 ticket.outcomes = self.replicas[i].process_batch(
-                    task[1], task[2], keys=task[3], embs=task[4])
+                    task[1], task[2], keys=task[3], embs=task[4],
+                    tags={"batch": ticket.id,
+                          "wait_us": round(wait_s * 1e6)})
             except ReplicaCrash as e:
                 # worker dies; the supervisor restarts the slot and
                 # redispatches the (side-effect-free) microbatch
@@ -511,7 +531,9 @@ class ServingFabric:
         with self._dispatch_lock:
             if replica is None:
                 replica = self._route_locked()
-            ticket = Ticket(replica=replica)
+            ticket = Ticket(replica=replica, id=self._next_ticket,
+                            submitted=time.monotonic())
+            self._next_ticket += 1
             self._tickets.append(ticket)
             self._queues[replica].put((ticket, prompts, guide_requests,
                                        keys, embs))

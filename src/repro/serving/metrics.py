@@ -24,11 +24,72 @@ fabric uses ``replica{i}/shadow/...`` prefixes so one shared registry
 carries every replica's queue gauges — which is exactly what the global
 adaptive flush policy (:class:`repro.core.shadow.AdaptiveDrainPolicy`)
 consumes: the learn replica reads every replica's staleness from here.
+
+Spans and sync counts sit beside the registry:
+
+* :func:`span` names a stretch of serve-path work in the profiler's
+  trace (``rar.*`` names, on the host plane, the clock the device's
+  launches are timed on). With no profiler session open it returns a
+  shared no-op, so an idle span costs a function call and a ``with``.
+* :func:`count_syncs` marks a blocking device→host fetch: it bumps
+  ``host/syncs/<site>`` in a registry (when the caller has one) and this
+  thread's tally (:func:`thread_syncs`), which the microbatch span reads
+  to tag itself with the fetches it waited on.
 """
 from __future__ import annotations
 
 import re
 import threading
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
+_tracing = _TraceAnnotation.is_enabled
+_local = threading.local()
+
+
+class _NoSpan:
+    """The span of a process with no profiler session: enters, leaves
+    and takes metadata without doing anything."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        return None
+
+    def set_metadata(self, **meta) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def span(name: str, **ids):
+    """A profiler span over the ``with`` block: a
+    ``jax.profiler.TraceAnnotation`` carrying ``ids`` as TraceMe
+    metadata (``rar.batch#batch=17#``) while a profiler session is open,
+    a shared no-op otherwise. Takes no lock and touches no device array.
+    ``set_metadata`` on the returned object adds metadata before the
+    span closes."""
+    if _tracing():
+        return _TraceAnnotation(name, **ids)
+    return _NO_SPAN
+
+
+def count_syncs(registry, site: str, n: int = 1) -> None:
+    """Count ``n`` blocking device→host fetches at ``site``: the
+    registry's ``host/syncs/<site>`` counter (skipped when ``registry``
+    is None) and the calling thread's tally."""
+    if registry is not None:
+        registry.counter("host/syncs/" + site).inc(n)
+    _local.syncs = getattr(_local, "syncs", 0) + n
+
+
+def thread_syncs() -> int:
+    """Blocking fetches counted on the calling thread so far."""
+    return getattr(_local, "syncs", 0)
 
 
 class Counter:

@@ -25,7 +25,9 @@ Lifecycle: **arrival → admit → close → dispatch → resolve.**
   flush) closes batches.
 - **dispatch** — a closed batch is submitted to the fabric unchanged
   through ``submit(prompts, guide_requests, keys=, embs=, replica=)``;
-  admission→dispatch queueing delay is recorded per request.
+  admission→dispatch queueing delay is recorded per request
+  (``sched/queue_delay_ms``: the wall wait from the due instant to the
+  submit when paced, the virtual wait otherwise).
 - **resolve** — tickets are waited in dispatch order and
   admission→resolve end-to-end latency recorded; outcomes return in
   admission order.
@@ -261,7 +263,13 @@ class ContinuousBatcher:
                                     replica=batch.replica)
         for r in reqs:
             r.dispatch_s = t
-            qd_ms = max(0.0, (t - r.arrival_s) * 1e3)
+            # paced: the wall wait from the request's due instant to its
+            # submit, a close that runs late included; unpaced: virtual
+            if self.pace:
+                qd_ms = (submit_wall - self._t0_wall - r.arrival_s) * 1e3
+            else:
+                qd_ms = (t - r.arrival_s) * 1e3
+            qd_ms = max(0.0, qd_ms)
             self._m_qd.observe(qd_ms)
             self._stream_hist(r.stream)[0].observe(qd_ms)
         self.dispatched += len(reqs)

@@ -192,6 +192,27 @@ def test_slo_close_fires_at_the_oldest_members_deadline():
     assert bat.closes["slo"] == 1
 
 
+@pytest.mark.parametrize("pace", [False, True])
+def test_queue_delay_records_the_wall_wait_when_paced(pace):
+    """A close that runs 0.5 s late: paced, ``sched/queue_delay_ms``
+    holds the wall wait from the due instant to the submit (the budget
+    plus the lateness); unpaced, the virtual wait (the budget alone)."""
+    import time
+    reg = MetricsRegistry()
+    bat = ContinuousBatcher(_FakeFabric(), microbatch=8, slo_ms=20.0,
+                            registry=reg, pace=pace)
+    bat._t0_wall = time.monotonic() - 0.5     # the arrival was 0.5 s ago
+    bat.admit(Request(arrival_s=0.0, stream=0, prompt=[0] * 3,
+                      guide_request=None, key=0, index=0))
+    bat.advance(0.03)
+    h = reg.histogram("sched/queue_delay_ms")
+    assert h.count == 1
+    if pace:
+        assert h.total >= 500.0
+    else:
+        assert h.total == pytest.approx(20.0)
+
+
 def test_priority_tightens_the_queueing_budget():
     fab = _FakeFabric()
     bat = ContinuousBatcher(fab, microbatch=8, slo_ms=40.0,

@@ -50,7 +50,11 @@ def test_engine_jit_cache_and_accounting(small_model, rng):
     t2 = jnp.asarray(rng.integers(1, cfg.vocab_size, (2, 16)), jnp.int32)
     engine.generate({"tokens": t2}, max_new=2)
     assert len(engine._jitted) == 2
-    assert engine.flops_spent > 0
+    # a forward pass: 2 N per token, a third of the training count
+    assert engine.flops_spent == pytest.approx(
+        engine.tokens_processed * 2 * cfg.active_param_count())
+    assert engine.flops_spent == pytest.approx(
+        engine.tokens_processed * cfg.flops_per_token() / 3)
 
 
 def test_generate_bucketed_matches_per_prompt(small_model, rng):
