@@ -25,9 +25,12 @@ Consequences:
   re-materialized the store on *every* query, doubling HBM traffic);
 * the ``guides_only`` view is a different ``required`` bit set on the same
   mask plane — no per-query (C,) mask combine;
-* writes (:func:`add`, :func:`add_batch`, :func:`mark_soft`,
-  :func:`touch`) scatter directly into the padded buffers, O(K·E) per
-  commit, never O(C·E).
+* writes scatter directly into the padded buffers. A drain epoch
+  applied through :class:`CommitStream` is one program that donates the
+  store, so it updates the buffers in place, O(K·E) per commit. The
+  value-semantics calls (:func:`add`, :func:`add_batch`,
+  :func:`mark_soft`, :func:`touch`, a direct :meth:`CommitBuffer.apply`)
+  leave their input store valid, so XLA copies the store first: O(C·E).
 
 Static shapes keep every operation jit-compatible; the similarity search
 is a fused cosine/top-1 over the full store — the Pallas kernel in
@@ -106,10 +109,13 @@ class MemoryState:
         a host counter: occupancy via ``CommitStream.commits`` +
         ``RAR._ptr_base`` (one ``int(ptr)`` at construction, never per
         request), epoch progress via ``CommitBuffer.epoch``/
-        ``entries_applied``. The remaining ``device_get(state.ptr)`` in
-        :meth:`CommitBuffer.apply_ops` sits on the drain path (per epoch,
-        off the serve sweep), and :attr:`size_fast` transfers one scalar
-        for shutdown/CLI reporting only."""
+        ``entries_applied``. A :class:`CommitStream` keeps a host mirror
+        of the ring pointer (one ``device_get`` when it first applies, and
+        one per grow), so a drain epoch reads no pointer either; only
+        callers without a stream (journal recovery, a direct
+        :meth:`CommitBuffer.apply`) read it per epoch, and
+        :attr:`size_fast` transfers one scalar for shutdown/CLI reporting
+        only."""
         return int(jnp.sum(self.valid))
 
     @property
@@ -338,7 +344,9 @@ def grow_memory(state: MemoryState, new_capacity: int
                                      guide_len=G))
     if ptr <= C:
         order = jnp.arange(C, dtype=jnp.int32)
-        new_ptr = state.ptr
+        # a buffer of its own: a later donating commit of the grown
+        # store must not delete the old store's pointer
+        new_ptr = jnp.asarray(ptr, jnp.int32)
         remap = jnp.arange(C, dtype=jnp.int32)
     else:
         shift = ptr % C
@@ -366,6 +374,78 @@ def _touch_jit(state: MemoryState, index: jax.Array,
                now: jax.Array) -> MemoryState:
     return dataclasses.replace(state,
                                added_at=state.added_at.at[index].set(now))
+
+
+#: fewest rows an epoch's operand matrix is padded to: with microbatches
+#: of up to 8 requests every inline drain epoch runs one program
+_EPOCH_MIN_ROWS = 8
+
+
+def _epoch_operands(state, records, softs, touches):
+    """Pack one epoch's ops into the single int32 operand of
+    :func:`_epoch_body` (one host→device transfer). Row r holds insert r
+    — [emb bits (Ep) | guide (G) | mask bits | hard | now] — then
+    soft-clear r's index and touch r's (index, now). Rows are a
+    power-of-two bucket of at least :data:`_EPOCH_MIN_ROWS`; a kind's
+    rows past its count are padding: mask bits 0 for an insert, index C
+    (one past the ring, dropped by the scatter) for a flag op."""
+    import numpy as np
+
+    Ep, C, G = state.emb.shape[1], state.capacity, state.guide.shape[1]
+    n = max(len(records), len(softs), len(touches))
+    rows = max(_EPOCH_MIN_ROWS, 1 << (n - 1).bit_length())
+    ops = np.zeros((rows, Ep + G + 6), np.int32)
+    k = len(records)
+    if k:
+        embs = np.stack([np.asarray(r[1], np.float32) for r in records])
+        ops[:k, :embs.shape[1]] = embs.view(np.int32)
+        ops[:k, Ep:Ep + G] = np.stack([np.asarray(r[2], np.int32)
+                                       for r in records])
+        ops[:k, Ep + G] = [MASK_VALID | (MASK_GUIDE if r[3] else 0)
+                           for r in records]
+        ops[:k, Ep + G + 1] = [r[4] for r in records]
+        ops[:k, Ep + G + 2] = [r[0] for r in records]
+    ops[:, Ep + G + 3:Ep + G + 5] = C
+    ops[:len(softs), Ep + G + 3] = softs
+    ops[:len(touches), Ep + G + 4] = list(touches)
+    ops[:len(touches), Ep + G + 5] = list(touches.values())
+    return ops
+
+
+def _epoch_body(state: MemoryState, ops: jax.Array) -> MemoryState:
+    """One drain epoch as one program, in the value-semantics order:
+    inserts at ``(ptr + i) % C`` in ring order, then the soft-clears,
+    then the touches; ``ptr`` advances by the real insert count (rows
+    with mask bits, which always carry ``MASK_VALID``). Padding rows
+    scatter out of range and are dropped. ``ops`` as
+    :func:`_epoch_operands` packs it; at most C inserts per call."""
+    Cp, Ep = state.emb.shape
+    C, G = state.capacity, state.guide.shape[1]
+    meta = ops[:, Ep:]
+    bits = meta[:, G]
+    row = jnp.arange(ops.shape[0], dtype=jnp.int32)
+    slot = jnp.where(bits != 0, (state.ptr + row) % C, Cp)
+
+    def put(a, index, value):
+        return a.at[index].set(value, mode="drop")
+
+    return MemoryState(
+        emb=put(state.emb, slot,
+                jax.lax.bitcast_convert_type(ops[:, :Ep], jnp.float32)),
+        mask=state.mask.at[slot, 0].set(bits, mode="drop"),
+        guide=put(state.guide, slot, meta[:, :G]),
+        hard=put(put(state.hard, slot, meta[:, G + 1] != 0),
+                 meta[:, G + 3], False),
+        added_at=put(put(state.added_at, slot, meta[:, G + 2]),
+                     meta[:, G + 4], meta[:, G + 5]),
+        ptr=state.ptr + jnp.sum(bits != 0, dtype=jnp.int32),
+    )
+
+
+# one body, two programs: the stream's path gives its store up (the
+# buffers are updated in place); every other caller keeps its input
+_epoch_jit = jax.jit(_epoch_body)
+_epoch_donated_jit = jax.jit(_epoch_body, donate_argnums=0)
 
 
 # ---------------------------------------------------------------------------
@@ -410,6 +490,13 @@ class CommitBuffer:
       inserts ever applied on the host, so serve-loop progress logging
       can report ring occupancy without the ``size_fast`` device-scalar
       sync.
+    * **Who donates** — a :class:`MemoryState` takes an epoch as one
+      fused program. :meth:`apply` keeps value semantics (the input store
+      stays valid, so XLA copies it). :meth:`apply_ops` with
+      ``donate=True`` gives the store up and updates it in place: only
+      an owner of the store passes it — :class:`CommitStream`, the
+      process fabric's worker mirror, journal replay — and never reads
+      the input again.
 
     Single-writer discipline: one thread stages and applies at a time
     (the drainer); readers only need :attr:`epoch`/:attr:`entries_applied`
@@ -488,21 +575,32 @@ class CommitBuffer:
         (new) store and the number of entries inserted. Ops land in
         deterministic order (see class docstring); inserts are chunked at
         ring capacity so an epoch larger than the ring degrades to the
-        sequential FIFO result instead of a self-overwriting scatter."""
+        sequential FIFO result instead of a self-overwriting scatter.
+        Value semantics: ``state`` stays valid (the store is copied)."""
         if not self.pending:
             return state, 0
         return self.apply_ops(state, *self.take_ops())
 
-    def apply_ops(self, state, records, soft_clears, touches):
+    def apply_ops(self, state, records, soft_clears, touches, *,
+                  ptr: int | None = None, donate: bool = False):
         """Apply one epoch's (already taken) ops to ``state``. This is
         the single code path both live drains and journal *recovery*
         replay go through — which is what makes a recovered store
-        byte-identical to the pre-crash one."""
-        import numpy as np
+        byte-identical to the pre-crash one.
 
+        A :class:`MemoryState` takes the whole epoch as one program
+        (:func:`_epoch_body`) per ``C`` inserts. ``donate`` is for a
+        caller that owns the store and gives it up — the commit stream,
+        the process fabric's worker mirror, journal replay: the program
+        then updates the buffers in place and ``state`` must not be read
+        again. ``ptr`` is the ring pointer as the caller's host mirror
+        has it; None reads it from the device (one sync). Mutable stores
+        (sharded, IVF) go through their own ``add_batch``/``mark_soft``/
+        ``touch`` methods."""
         records = sorted(records, key=lambda r: r[0])
         C = state.capacity
-        base_ptr = int(jax.device_get(state.ptr))
+        base_ptr = int(jax.device_get(state.ptr)) if ptr is None \
+            else int(ptr)
         end_ptr = base_ptr + len(records)
 
         def evicted(idx: int, snap) -> bool:
@@ -514,6 +612,47 @@ class CommitBuffer:
             snap = base_ptr if snap is None else min(int(snap), base_ptr)
             covered = end_ptr - snap
             return covered >= C or (idx - snap) % C < covered
+
+        softs = sorted({idx for _, idx, snap in soft_clears
+                        if not evicted(idx, snap)})
+        # duplicate touch targets dedupe last-now-wins (scatter order for
+        # duplicate indices is implementation-defined)
+        by_idx = {idx: now for now, idx, snap in
+                  sorted(touches, key=lambda t: t[:2])
+                  if not evicted(idx, snap)}
+        if isinstance(state, MemoryState):
+            state = self._apply_epoch(state, records, softs, by_idx,
+                                      donate)
+        else:
+            state = self._apply_each(state, records, softs, by_idx)
+        self.epoch += 1
+        self.entries_applied += len(records)
+        return state, len(records)
+
+    @staticmethod
+    def _apply_epoch(state, records, softs, touches, donate: bool):
+        """The fused epoch: one program per ``C`` inserts, the flag ops
+        riding on the last (so an epoch of at most C inserts is a single
+        call — all or nothing)."""
+        if not (records or softs or touches):
+            return state
+        run = _epoch_donated_jit if donate else _epoch_jit
+        C = state.capacity
+        starts = range(0, len(records), C) if records else [0]
+        for start in starts:
+            last = start + C >= len(records)
+            state = run(state, _epoch_operands(
+                state, records[start:start + C],
+                softs if last else [], touches if last else {}))
+        return state
+
+    @staticmethod
+    def _apply_each(state, records, softs, by_idx):
+        """A mutable store's epoch: its own scatters, inserts chunked at
+        capacity, then the soft-clears, then the touches."""
+        import numpy as np
+
+        C = state.capacity
 
         def po2_chunks(seq):
             """Split into power-of-two-sized runs (13 -> 8+4+1): the
@@ -539,23 +678,14 @@ class CommitBuffer:
                     jnp.asarray(np.asarray([r[4] for r in chunk], bool)),
                     jnp.asarray(np.asarray([r[0] for r in chunk],
                                            np.int32)))
-        softs = sorted({idx for _, idx, snap in soft_clears
-                        if not evicted(idx, snap)})
         for chunk in po2_chunks(softs):
             state = mark_soft(state, jnp.asarray(chunk, jnp.int32))
-        # duplicate touch targets dedupe last-now-wins (scatter order for
-        # duplicate indices is implementation-defined)
-        by_idx = {idx: now for now, idx, snap in
-                  sorted(touches, key=lambda t: t[:2])
-                  if not evicted(idx, snap)}
         for chunk in po2_chunks(sorted(by_idx)):
             state = touch(state,
                           jnp.asarray(chunk, jnp.int32),
                           jnp.asarray([by_idx[i] for i in chunk],
                                       jnp.int32))
-        self.epoch += 1
-        self.entries_applied += len(records)
-        return state, len(records)
+        return state
 
 
 # ---------------------------------------------------------------------------
@@ -812,9 +942,10 @@ class MemoryJournal:
                 continue                      # manifest only, no ops
             if rec["epoch"] <= epoch:
                 continue                      # snapshot already covers it
+            # the replay owns its store: each epoch updates it in place
             state, _ = replay.apply_ops(state, rec["records"],
                                         rec["soft_clears"],
-                                        rec["touches"])
+                                        rec["touches"], donate=True)
             replay.epoch = rec["epoch"]       # keep numbering exact
             if rec.get("manifest") is not None:
                 manifest = rec["manifest"]
@@ -853,6 +984,15 @@ class CommitStream:
     view; the serving fabric (:mod:`repro.serving.fabric`) passes one
     shared stream to all its replicas.
 
+    **The stream owns the store it applies to.** :meth:`apply` donates a
+    :class:`MemoryState` to the epoch program, which updates its buffers
+    in place, and hands the new store to every view: a store passed to
+    :meth:`apply` (or :meth:`apply_ops`) must not be read again, and
+    readers take the store from their view under :attr:`lock`, never
+    from a reference kept across an apply. The stream keeps a host
+    mirror of the ring pointer (:attr:`ptr_host`), so an epoch reads no
+    pointer from the device.
+
     With a :class:`MemoryJournal` attached the stream is
     crash-consistent: each epoch's ops are journaled (write-ahead,
     fsynced) before the in-memory apply, and the store is periodically
@@ -868,6 +1008,12 @@ class CommitStream:
         self.lock = threading.RLock()
         self.commits = 0             # entries ever committed (host-side)
         self._views: list = []       # controllers mirroring the store
+        # host mirror of the ring pointer of the store this stream last
+        # produced (``_store``): read from the device when the stream
+        # first applies (or is handed a store it did not produce),
+        # advanced by each epoch's inserts, re-read by ``grow``
+        self.ptr_host: int | None = None
+        self._store = None
         self.journal = journal
         self.fault_plan = fault_plan
         # engine-state exporter (set by the owning controller/fabric):
@@ -907,9 +1053,10 @@ class CommitStream:
         the kill-mid-epoch point the recovery property tests. Returns
         the new store.
 
-        A non-empty epoch is one ``rar.commit`` span; its seconds go to
-        the ``commit/apply_seconds`` histogram and the store pointer's
-        read in :meth:`CommitBuffer.apply_ops` counts as a
+        ``state`` must be the store the views hold; it is given up (see
+        the class docstring). A non-empty epoch is one ``rar.commit``
+        span; its seconds go to the ``commit/apply_seconds`` histogram.
+        Only the pointer mirror's first read counts as a
         ``host/syncs/commit`` fetch."""
         with self.lock:
             if not self.buffer.pending:
@@ -933,10 +1080,8 @@ class CommitStream:
                                    manifest)
         if self.fault_plan is not None:
             self.fault_plan.fire("commit_apply", epoch=epoch)
-        if isinstance(state.ptr, jax.Array):
-            count_syncs(self.metrics, "commit")
-        state, n = self.buffer.apply_ops(state, records, soft_clears,
-                                         touches)
+        in_place = isinstance(state, MemoryState)
+        state, n = self.apply_ops(state, records, soft_clears, touches)
         self.commits += n
         for v in self._views:
             v.memory = state
@@ -946,11 +1091,31 @@ class CommitStream:
         if self.metrics is not None:
             with self.metrics.lock:
                 self.metrics.counter("commit/epochs_applied").inc()
+                if in_place:
+                    self.metrics.counter("commit/epochs_in_place").inc()
                 self.metrics.counter("commit/entries_applied").inc(n)
                 self.metrics.gauge("commit/epoch").set(self.buffer.epoch)
         if self.journal is not None:
             self.journal.maybe_snapshot(state, self.buffer, manifest)
         return state
+
+    def apply_ops(self, state, records, soft_clears, touches):
+        """Apply one epoch's taken ops to the stream's own store, in
+        place: :meth:`CommitBuffer.apply_ops` with the host pointer
+        mirror and the store donated. ``state`` must not be read again.
+        Returns ``(new_state, inserted)``; broadcasts nothing (the
+        process fabric's worker mirror calls it directly)."""
+        with self.lock:
+            if self.ptr_host is None or state is not self._store:
+                if isinstance(state.ptr, jax.Array):
+                    count_syncs(self.metrics, "commit")
+                self.ptr_host = int(jax.device_get(state.ptr))
+            state, n = self.buffer.apply_ops(state, records, soft_clears,
+                                             touches, ptr=self.ptr_host,
+                                             donate=True)
+            self.ptr_host += n
+            self._store = state
+            return state, n
 
     def grow(self, state, new_capacity: int):
         """Grow the stream's store in place (capacity re-layout) and
@@ -971,6 +1136,7 @@ class CommitStream:
             else:
                 state, remap = state.grow(new_capacity)
             new_ptr = int(jax.device_get(state.ptr))
+            self.ptr_host, self._store = new_ptr, state
             for v in self._views:
                 v.memory = state
                 if hasattr(v, "_ptr_base"):
@@ -1028,6 +1194,8 @@ def open_journaled_stream(path: str, mem_cfg: MemoryConfig, *,
         state, epoch, entries, manifest = recovered
         stream.buffer.epoch = epoch
         stream.buffer.entries_applied = entries
+        stream.ptr_host = int(jax.device_get(state.ptr))
+        stream._store = state
     return stream, state, manifest
 
 
@@ -1100,7 +1268,9 @@ def query_topk_batch(state, embs: jax.Array, k: int,
 def add(state, emb: jax.Array, guide: jax.Array, has_guide: jax.Array,
         hard: jax.Array, now: jax.Array):
     """Insert one entry at the ring pointer (FIFO eviction). Scatters one
-    padded row in place — the store is never re-materialized."""
+    padded row; ``state`` stays valid, so a :class:`MemoryState` is copied
+    first. Only the commit stream's epoch (:meth:`CommitStream.apply`),
+    which gives its store up, never re-materializes the store."""
     if isinstance(state, MemoryState):
         return _add_jit(state, emb, guide, has_guide, hard, now)
     state.add(emb, guide, has_guide, hard, now)

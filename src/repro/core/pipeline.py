@@ -554,9 +554,11 @@ class MicrobatchRAR(RAR):
         # whose pre-epoch slot this epoch's scatter just evicted are
         # dropped (CommitBuffer contract). The apply, the commit-counter
         # bump and the broadcast to every subscribed replica view happen
-        # atomically under the stream's store lock.
+        # atomically under the stream's store lock; the view is read
+        # under it too, since the apply gives the store it reads up.
         self.shadow.note_probe_calls(probe_calls)
-        self.memory = self.commit_stream.apply(self.memory)
+        with self.commit_stream.lock:
+            self.memory = self.commit_stream.apply(self.memory)
 
     def _guide_probe_sweep(self, pending, still, probed_2a, settle) -> None:
         """Sweep 2 of a drain epoch (Case 2a): probe the weak tier with

@@ -711,12 +711,15 @@ class ServingFabric:
         immediately, otherwise it joins the synchronous round-robin."""
         weak, strong, embed_fn, route_weak_fn = self._replica_args
         i = len(self.replicas)
-        r = _FabricReplica(self, i, weak, strong, embed_fn,
-                           route_weak_fn, self.cfg,
-                           aligned_fn=self._aligned_fn,
-                           memory=self.learn.memory,
-                           commit_stream=self.commit_stream,
-                           fault_plan=self.fault_plan)
+        # under the stream lock: a commit gives the store it applies to
+        # up, so the view is read and subscribed between two epochs
+        with self.commit_stream.lock:
+            r = _FabricReplica(self, i, weak, strong, embed_fn,
+                               route_weak_fn, self.cfg,
+                               aligned_fn=self._aligned_fn,
+                               memory=self.learn.memory,
+                               commit_stream=self.commit_stream,
+                               fault_plan=self.fault_plan)
         self.replicas.append(r)
         self.health.append("healthy")
         self.spawned += 1

@@ -222,8 +222,9 @@ def _worker_loop(channel: FramedChannel, init: dict) -> None:
         if kind == "epoch":
             # broadcast drain epochs, coalesced: every epoch frame
             # already queued behind this one folds into a single
-            # apply_ops call. Records sort by logical time inside
-            # apply_ops and flag ops carry their own pointer snapshots,
+            # apply_ops call, in place on the worker's own store.
+            # Records sort by logical time inside apply_ops and flag
+            # ops carry their own pointer snapshots,
             # so the batched apply is byte-identical to applying the
             # epochs one at a time — the same path live drains and WAL
             # recovery use — while amortizing the per-apply dispatch
@@ -248,7 +249,7 @@ def _worker_loop(channel: FramedChannel, init: dict) -> None:
                 touches += more_t
                 n += m
             with stream.lock:
-                rep.memory, _ = stream.buffer.apply_ops(
+                rep.memory, _ = stream.apply_ops(
                     rep.memory, records, soft_clears, touches)
                 stream.buffer.epoch = epoch
                 stream.commits += n
@@ -322,7 +323,7 @@ def _worker_loop(channel: FramedChannel, init: dict) -> None:
             backlog.append(nxt)       # serves keep their FIFO order
         if acc_epoch is not None:
             with stream.lock:
-                rep.memory, _ = stream.buffer.apply_ops(
+                rep.memory, _ = stream.apply_ops(
                     rep.memory, acc_r, acc_s, acc_t)
                 stream.buffer.epoch = acc_epoch
                 stream.commits += acc_n

@@ -20,6 +20,7 @@ import os
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 from test_pipeline import MEM_FIELDS, make_stream, run_batched
@@ -646,11 +647,14 @@ def run_journaled(stream, path, fault_plan=None, snapshot_every=8,
               make_cfg(journal_path=path, snapshot_every=snapshot_every,
                        **cfg_kw),
               fault_plan=fault_plan)
-    snapshots = {0: rar.memory}        # state after each commit epoch
+    # host copies of the store after each commit epoch: the stream
+    # updates its store in place, so a device reference goes stale
+    snapshots = {0: jax.device_get(rar.memory)}
     for s, x in stream:
         holder["emb"] = skill_emb(s)
         rar.process(prompt(s, x), greq(s), key=(s, x))
-        snapshots[rar.commit_stream.buffer.epoch] = rar.memory
+        snapshots[rar.commit_stream.buffer.epoch] = \
+            jax.device_get(rar.memory)
     return rar, snapshots
 
 

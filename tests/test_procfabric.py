@@ -270,7 +270,10 @@ def test_lease_expiry_detects_hung_worker():
     o = one(fab, 0, 1)
     assert o.case == "case1"
     deadline = time.monotonic() + 30
-    while fab.lease_expiries == 0 and time.monotonic() < deadline:
+    # the monitor counts the expiry before it respawns the slot: wait
+    # out the respawn too, or the check races it
+    while (fab.lease_expiries == 0 or fab.restarts == 0) and \
+            time.monotonic() < deadline:
         time.sleep(0.05)
     assert fab.lease_expiries >= 1
     assert fab.deaths == 1 and fab.restarts == 1

@@ -74,7 +74,8 @@ SECOND_SYNCS = {"embed": 3,          # one per embed_fn call
                 "lookup": 2,         # the serve read and the 2a read
                 "engine": 6,         # strong 1 + weak serve 2 (lengths
                                      # 3, 6) + probe 1 + guide 1 + probe 1
-                "commit": 1}
+                "commit": 0}         # the stream's host pointer mirror
+                                     # was read at the first commit
 
 
 def _spans(log_dir) -> list[tuple]:

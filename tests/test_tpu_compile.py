@@ -9,6 +9,7 @@ module is imported: only one process at a time may load the TPU compiler
 library, and every test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
+from repro.core import memory as mem
 from repro.core.memory_sharded import (AXIS, _query_topk_batch_sharded,
                                        _query_topk_sharded, make_memory_mesh)
 from repro.kernels import ops
@@ -141,3 +143,30 @@ def test_sharded_topk_compiles_across_four_chips(topo, b):
     # each chip holds one shard of the store, not the whole of it
     args = compiled.memory_analysis().argument_size_in_bytes
     assert padded_rows(cs) * ep * 4 <= args < SHARDS * cs * ep * 4
+
+
+def test_commit_epoch_updates_the_store_in_place_on_v5e(one_chip):
+    """The commit stream's donated epoch program at the benchmark's store
+    size (2^20 rows, guide blocks of 8): every store byte is aliased from
+    input to output and no store array is copied, where the
+    value-semantics program of the same body aliases nothing."""
+    rows, guide_len = 1 << 20, 8
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    store = mem.MemoryState(
+        emb=arg((padded_rows(rows), padded_lanes(E)), jnp.float32),
+        mask=arg((padded_rows(rows), 1), jnp.int32),
+        guide=arg((rows, guide_len), jnp.int32),
+        hard=arg((rows,), jnp.bool_), added_at=arg((rows,), jnp.int32),
+        ptr=arg((), jnp.int32))
+    ops = arg((8, padded_lanes(E) + guide_len + 6), jnp.int32)
+    store_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(store)) - 4   # all but ptr
+    donated = mem._epoch_donated_jit.lower(store, ops).compile()
+    assert donated.memory_analysis().alias_size_in_bytes >= store_bytes
+    assert not re.search(r"copy(-start)?\(%state_(emb|mask|guide|hard|"
+                         r"added_at)", donated.as_text())
+    plain = mem._epoch_jit.lower(store, ops).compile()
+    assert plain.memory_analysis().alias_size_in_bytes == 0
